@@ -3,15 +3,17 @@
 For each dispatch-table op (``attention``, ``decode_attention``, ``ssd``)
 at serving-representative shapes, measures median device time per backend
 (``xla`` = jnp reference, ``pallas`` = compiled kernel), records analytic
-FLOPs / HBM bytes and the TPU-v5e roofline bound
-(``max(flops/peak_flops, bytes/hbm_bw)``), and runs an interpret-mode
-parity check (``pallas_interpret`` vs ``xla`` max abs error) so the
-artifact itself witnesses numerical agreement.
+FLOPs / HBM bytes and the roofline bound
+(``max(flops/peak_flops, bytes/hbm_bw)``, peaks from
+``repro.launch.mesh.peak_rates`` by the chip's ``device_kind``), and runs
+an interpret-mode parity check (``pallas_interpret`` vs ``xla`` max abs
+error) so the artifact itself witnesses numerical agreement.
 
-Compiled Pallas only lowers on TPU/GPU; on a CPU host the ``pallas_s``
+Compiled Pallas only lowers on a TPU; on a CPU host the ``pallas_s``
 column is ``null`` (interpret mode is an emulation path — timing it would
-be meaningless) while the parity check and the ``xla`` timings still run,
-so the artifact stays reproducible everywhere.
+be meaningless), the bound is the v5e one asked for by name, and no
+``*_vs_bound`` ratio is written (a CPU time over a chip bound means
+nothing); the parity check and the ``xla`` timings still run.
 
     python -m benchmarks.bench_kernels [--smoke] [--reps N] [--out PATH]
 
@@ -33,7 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
-from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import V5E, peak_rates
 
 DTYPE = jnp.bfloat16
 BYTES = 2                     # bf16
@@ -148,8 +150,13 @@ def main() -> None:
     args = ap.parse_args()
     reps = args.reps or (5 if args.smoke else 20)
 
-    platform = jax.devices()[0].platform
-    compiled_ok = platform in ("tpu", "gpu")
+    dev = jax.devices()[0]
+    platform = dev.platform
+    compiled_ok = platform == "tpu"
+    # a chip is bounded by its own peaks; a CPU host records the v5e bound
+    # it is asked for by name, and no time of its own against it
+    roofline_kind = dev.device_kind if compiled_ok else V5E
+    peak = peak_rates(roofline_kind)
     if not compiled_ok:
         print(f"[bench_kernels] platform={platform}: compiled Pallas "
               f"cannot lower here; pallas_s will be null (interpret "
@@ -159,19 +166,19 @@ def main() -> None:
     rows = []
     for op_name, label, op_args, op_kw, (flops, bytes_) in _cases(args.smoke):
         fn = getattr(ops, op_name)
-        bound_s = max(flops / PEAK_FLOPS_BF16, bytes_ / HBM_BW)
+        bound_s = max(flops / peak.flops_bf16, bytes_ / peak.hbm_bw)
         row = {
             "op": op_name, "case": label, "dtype": "bfloat16",
             "flops": flops, "hbm_bytes": bytes_,
             "roofline_bound_s": bound_s,
-            "roofline_bound": ("hbm" if bytes_ / HBM_BW
-                               >= flops / PEAK_FLOPS_BF16 else "compute"),
+            "roofline_bound": ("hbm" if bytes_ / peak.hbm_bw
+                               >= flops / peak.flops_bf16 else "compute"),
         }
         backends = ("xla", "pallas") if compiled_ok else ("xla",)
         for backend in backends:
             t = _median_time(fn, op_args, dict(op_kw, backend=backend), reps)
             row[f"{backend}_s"] = t
-            row[f"{backend}_vs_bound"] = t / bound_s
+            row[f"{backend}_vs_bound"] = t / bound_s if compiled_ok else None
         if compiled_ok:
             row["pallas_speedup"] = row["xla_s"] / row["pallas_s"]
         else:
@@ -199,10 +206,12 @@ def main() -> None:
         "smoke": bool(args.smoke),
         "python": sys.version.split()[0],
         "jax": jax.__version__,
-        "device": jax.devices()[0].platform,
+        "device": platform,
+        "device_kind": dev.device_kind,
+        "roofline_device_kind": roofline_kind,
         "reps": reps,
-        "peak_flops_bf16": PEAK_FLOPS_BF16,
-        "hbm_bw": HBM_BW,
+        "peak_flops_bf16": peak.flops_bf16,
+        "hbm_bw": peak.hbm_bw,
         "metric": "median wall seconds per dispatch (block_until_ready), "
                   "vs analytic roofline bound max(flops/peak, bytes/bw)",
         "rows": rows,

@@ -55,7 +55,8 @@ from .common import timer  # noqa: F401  (also bootstraps sys.path for src/)
 from repro.core import (BatchedJaxBackend, ClusterConfig, JaxBackend,
                         StubBackend, StubBatchedBackend)
 from repro.configs import get_config
-from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import V5E, peak_rates
 from repro.serving import (ServedModel, ServingApp, multitenant_apps,
                            smoke_apps)
 from repro.sim import Experiment, run_sweep, simulate
@@ -154,6 +155,7 @@ def _decode_roofline(cfg, max_batch: int) -> dict:
     pays — B requests share one weight read per token."""
     n = cfg.active_param_count()
     weight_bytes = 2 * n
+    peak = peak_rates(V5E)
     per_batch = {}
     b = 1
     while b <= max_batch:
@@ -161,16 +163,18 @@ def _decode_roofline(cfg, max_batch: int) -> dict:
         per_batch[b] = {
             "flops": flops,
             "hbm_bytes": weight_bytes,
-            "bound_s": max(flops / PEAK_FLOPS_BF16, weight_bytes / HBM_BW),
-            "bound": ("hbm" if weight_bytes / HBM_BW
-                      >= flops / PEAK_FLOPS_BF16 else "compute"),
+            "bound_s": max(flops / peak.flops_bf16,
+                           weight_bytes / peak.hbm_bw),
+            "bound": ("hbm" if weight_bytes / peak.hbm_bw
+                      >= flops / peak.flops_bf16 else "compute"),
         }
         b *= 2
     return {
         "model": "mamba2-370m (reduced)",
         "active_params": n,
-        "peak_flops_bf16": PEAK_FLOPS_BF16,
-        "hbm_bw": HBM_BW,
+        "device_kind": V5E,
+        "peak_flops_bf16": peak.flops_bf16,
+        "hbm_bw": peak.hbm_bw,
         "note": "per-decode-step lower bound: max(2*N*B/peak_flops, "
                 "2*N/hbm_bw); reduced configs are far from saturating a "
                 "v5e, so measured step times sit well above bound_s — the "
@@ -282,16 +286,15 @@ def main() -> None:
                          "(docs/SERVING.md); recorded per row in data_plane")
     ap.add_argument("--workers", type=int, default=1,
                     help="run sweep cells in N worker processes "
-                         "(repro.sim.run_sweep(workers=N)).  Requires "
-                         "picklable cells: works with the stub backends; "
-                         "a shared live JAX backend falls back to "
-                         "sequential with a warning (cells would not share "
-                         "one calibration anyway)")
+                         "(repro.sim.run_sweep(workers=N)).  Works with "
+                         "the stub backends; JAX backend cells run in this "
+                         "process with a warning (one process per chip)")
     ap.add_argument("--out", default="",
                     help="JSON artifact path (default: BENCH_serving.json "
                          "at the repo root, or BENCH_serving.partial.json "
                          "with --smoke)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     apps = smoke_apps() if args.smoke else multitenant_apps()
     backend = _make_backend(args.backend, args.batch_window, args.max_batch,

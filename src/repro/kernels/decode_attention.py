@@ -38,18 +38,23 @@ def _dec_kernel(vlen_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    valid_len = vlen_ref[0]
-    k_pos = ik * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_k, 1), 0)
+    valid_len = vlen_ref[pl.program_id(0)]
     live = (ik * block_k) < valid_len
 
     @pl.when(live)
     def _compute():
-        valid = k_pos < jnp.minimum(valid_len, L)
+        G = q_ref.shape[0]
+        n = jnp.minimum(valid_len, L)
+        # the key mask as a column (sanitizes K/V rows) and as score columns;
+        # both come from iotas, since Mosaic will not reshape (bk,1)->(1,bk)
+        valid = ik * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_k, 1), 0) < n
+        valid_s = ik * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (G, block_k), 1) < n
         q = q_ref[...].astype(f32)                       # (G, hd)
         k = jnp.where(valid, k_ref[...].astype(f32), 0.0)  # (bk, hd)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, bk)
-        s = jnp.where(valid.reshape(1, -1), s, NEG_INF)
+        s = jnp.where(valid_s, s, NEG_INF)
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -86,23 +91,27 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     vlen = jnp.repeat(valid_len.astype(jnp.int32), Hkv)    # (B*Hkv,)
 
     kernel = functools.partial(_dec_kernel, block_k=bk, L=L)
+    # valid lengths ride in SMEM as a scalar-prefetch operand: every index
+    # map and the kernel body see the whole (B*Hkv,) array
     out = pl.pallas_call(
         kernel,
-        grid=(B * Hkv, nk),
-        in_specs=[
-            pl.BlockSpec((1,), lambda h, ik: (h,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((None, G, hd), lambda h, ik: (h, 0, 0)),
-            pl.BlockSpec((None, bk, hd), lambda h, ik: (h, ik, 0)),
-            pl.BlockSpec((None, bk, hd), lambda h, ik: (h, ik, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, G, hd), lambda h, ik: (h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B * Hkv, nk),
+            in_specs=[
+                pl.BlockSpec((None, G, hd), lambda h, ik, vl: (h, 0, 0)),
+                pl.BlockSpec((None, bk, hd), lambda h, ik, vl: (h, ik, 0)),
+                pl.BlockSpec((None, bk, hd), lambda h, ik, vl: (h, ik, 0)),
+            ],
+            out_specs=pl.BlockSpec((None, G, hd),
+                                   lambda h, ik, vl: (h, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((G, 1), f32),
+                pltpu.VMEM((G, 1), f32),
+                pltpu.VMEM((G, hd), f32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((B * Hkv, G, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), f32),
-            pltpu.VMEM((G, 1), f32),
-            pltpu.VMEM((G, hd), f32),
-        ],
         interpret=interpret,
     )(vlen, qt, kt, vt)
     return out.reshape(B, Hkv, G, hd).reshape(B, Hq, hd)

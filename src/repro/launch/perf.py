@@ -24,7 +24,7 @@ from typing import Any, Dict, Optional
 import jax
 
 from ..configs import get_config
-from .mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_production_mesh
+from .mesh import V5E, make_production_mesh, peak_rates
 from .roofline import (_compose, _measure, _probe_cfg, _type_key,
                        model_flops)
 
@@ -72,10 +72,11 @@ def measure_variant(arch: str, shape: str, *, mesh=None,
              "coll": {k: (probe["coll"][k] - base["coll"][k]) / len(idxs)
                       for k in probe["coll"]}}, extra))
     tot = _compose(base, deltas)
+    peak = peak_rates(V5E)
     out = {
-        "compute_s": tot["flops"] / PEAK_FLOPS_BF16,
-        "memory_s": tot["bytes"] / HBM_BW,
-        "collective_s": tot["coll_bytes"] / ICI_BW,
+        "compute_s": tot["flops"] / peak.flops_bf16,
+        "memory_s": tot["bytes"] / peak.hbm_bw,
+        "collective_s": tot["coll_bytes"] / peak.ici_bw,
         "hlo_flops_dev": tot["flops"],
         "hlo_bytes_dev": tot["bytes"],
         "coll_bytes_dev": tot["coll_bytes"],

@@ -30,7 +30,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from ..configs import ARCH_IDS, INPUT_SHAPES, get_config, shape_applicable
 from ..models.config import LayerGroup, ModelConfig
-from .mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_production_mesh
+from .mesh import V5E, make_production_mesh, peak_rates
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                            "results")
@@ -216,9 +216,10 @@ def analyze(arch: str, shape: str, use_cache: bool = True) -> Dict[str, Any]:
     tot = _compose(base, deltas)
     n_dev = mesh.devices.size
 
-    compute_s = tot["flops"] / PEAK_FLOPS_BF16
-    memory_s = tot["bytes"] / HBM_BW
-    coll_s = tot["coll_bytes"] / ICI_BW
+    peak = peak_rates(V5E)
+    compute_s = tot["flops"] / peak.flops_bf16
+    memory_s = tot["bytes"] / peak.hbm_bw
+    coll_s = tot["coll_bytes"] / peak.ici_bw
     terms = {"compute_s": compute_s, "memory_s": memory_s,
              "collective_s": coll_s}
     bottleneck = max(terms, key=terms.get)

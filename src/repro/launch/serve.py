@@ -13,6 +13,7 @@ from ..configs import ARCH_IDS, get_config
 from ..core import ClusterConfig
 from ..serving import ServedModel, ServingApp
 from ..sim import Experiment, simulate
+from .cache import enable_compile_cache
 
 
 def main() -> None:
@@ -50,6 +51,7 @@ def main() -> None:
                          "the duration for the jax backend — real compiles "
                          "take seconds and arrivals start at t=0 — else 0")
     args = ap.parse_args()
+    enable_compile_cache()
     duration = args.requests / args.rps
     warmup = args.warmup
     real_jax = args.backend in ("jax", "jax-batched")

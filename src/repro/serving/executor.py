@@ -62,12 +62,15 @@ class ModelInstance:
 
     def setup(self, seed: int = 0) -> float:
         """Compile + initialize.  Returns real wall time (the sandbox setup
-        overhead that Archipelago moves off the critical path)."""
+        overhead that Archipelago moves off the critical path).  Weights
+        already set on the instance (shared with another instance of the
+        same function) are kept; otherwise they are drawn from ``seed``."""
         t0 = time.perf_counter()
         sm = self.served
         cfg = sm.cfg
-        key = jax.random.PRNGKey(seed)
-        self.params = jax.jit(lambda k: init_params(cfg, k))(key)
+        if self.params is None:
+            key = jax.random.PRNGKey(seed)
+            self.params = jax.jit(lambda k: init_params(cfg, k))(key)
         max_len = sm.prompt_len + sm.gen_len
         self.cache0 = init_cache(cfg, sm.batch, max_len)
 
@@ -177,7 +180,9 @@ class BatchingJaxExecutor:
     Amortizing weight reads over the whole batch is why this sustains a
     multiple of the per-invocation executor's throughput once batches form
     — see ``benchmarks/bench_serving.py``'s batched-vs-unbatched
-    comparison.
+    comparison.  The buckets of one function share one copy of its
+    weights (a published-width model would not fit device memory once per
+    bucket).
     """
 
     def __init__(self, served: Dict[str, ServedModel], max_batch: int = 8):
@@ -186,6 +191,7 @@ class BatchingJaxExecutor:
         self.served = served
         self.max_batch = max_batch
         self._instances: Dict[Tuple[str, int], ModelInstance] = {}
+        self._params: Dict[str, Any] = {}     # fn_name -> shared weights
         # calibration medians per (fn_name, bucket) — measured batched
         # execution seconds, recorded for reporting/analysis
         self.bucket_exec_s: Dict[Tuple[str, int], float] = {}
@@ -205,8 +211,10 @@ class BatchingJaxExecutor:
         key = (fn_name, bucket)
         inst = self._instances.get(key)
         if inst is None:
-            inst = ModelInstance(replace(self.served[fn_name], batch=bucket))
+            inst = ModelInstance(replace(self.served[fn_name], batch=bucket),
+                                 params=self._params.get(fn_name))
             inst.setup()
+            self._params.setdefault(fn_name, inst.params)
             self._instances[key] = inst
         return inst
 

@@ -37,7 +37,8 @@ from typing import (Any, Callable, Dict, List, Mapping, Optional, Sequence,
                     Tuple, Union)
 
 from ..core.autoscale import AutoscaleConfig
-from ..core.backends import ExecutionBackend, resolve_backend
+from ..core.backends import (ExecutionBackend, JaxBackend, get_backend,
+                             resolve_backend)
 from ..core.cluster import ClusterConfig
 from ..core.fault import FaultInjector, FaultPlan, recovery_summary
 from ..core.lbs import LBSConfig, LoadBalancer
@@ -721,6 +722,15 @@ def _picklable(v: Any) -> bool:
     return True
 
 
+def _drives_device(exp: Experiment) -> bool:
+    """Whether the cell's backend is a JAX data plane (``jax``,
+    ``jax-batched``, named or an instance): only one process can hold a
+    chip, so such a cell must run in the process that already does."""
+    b = exp.backend
+    return issubclass(get_backend(b) if isinstance(b, str) else type(b),
+                      JaxBackend)
+
+
 def _run_cell(exp: Experiment) -> Dict[str, Any]:
     """Worker-process entry point: one fresh simulation, serialized through
     the lossless ``to_dict`` round-trip (the live ``sim`` handle never
@@ -742,7 +752,8 @@ def run_sweep(base: Experiment, axes: Mapping[str, Sequence[Any]],
     ``Experiment``s to pickle: use *named* workload factories and *named*
     backends; a base experiment carrying live objects (a shared
     ``ExecutionBackend`` instance, a spec with closure hooks) falls back to
-    sequential execution with a warning.  ``keep_sim=True`` retains the
+    sequential execution with a warning, as does any cell whose backend is
+    a JAX data plane (one process per chip).  ``keep_sim=True`` retains the
     live per-cell results (including ``.sim``) on
     ``SweepResult.experiment_results`` for bespoke analysis and therefore
     always runs sequentially in-process."""
@@ -757,6 +768,14 @@ def run_sweep(base: Experiment, axes: Mapping[str, Sequence[Any]],
             f"simulation handles that cannot cross a process boundary; "
             f"falling back to sequential execution",
             RuntimeWarning, stacklevel=2)
+    if use_pool and any(_drives_device(exp) for _, exp in cells):
+        import warnings
+        warnings.warn(
+            f"run_sweep(workers={workers}): cells with a JAX data-plane "
+            f"backend run in this process (only one process can hold a "
+            f"chip); falling back to sequential execution",
+            RuntimeWarning, stacklevel=2)
+        use_pool = False
     if use_pool:
         import pickle
         try:

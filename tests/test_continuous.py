@@ -219,6 +219,30 @@ def test_run_batch_seed_ignores_coalescing_order():
         == batch_seed(i.inv_id for i in invs)
 
 
+def test_batch_buckets_share_one_weight_copy():
+    """One weight copy per function: every bucket holds the same ``params``
+    object, equal to what a bucket drawing its own weights gets (the same
+    ``PRNGKey(0)`` init), so sharing changes no result."""
+    pytest.importorskip("jax")
+    import jax
+    import numpy as np
+    from repro.serving import smoke_apps
+    from repro.serving.executor import BatchingJaxExecutor, ModelInstance
+
+    served = dict(smoke_apps()[0].models)
+    name = next(iter(served))
+    ex = BatchingJaxExecutor(served, max_batch=2)
+    insts = [ex.ensure_instance(name, b) for b in ex.buckets()]
+    assert len(insts) == 2
+    assert all(i.params is insts[0].params for i in insts)
+    own = ModelInstance(insts[-1].served)
+    own.setup()
+    assert own.params is not insts[-1].params
+    assert all(jax.tree.leaves(jax.tree.map(
+        lambda a, b: bool(np.array_equal(a, b)), own.params,
+        insts[-1].params)))
+
+
 # -- real JAX continuous serving ---------------------------------------------
 
 

@@ -65,3 +65,17 @@ def test_param_count_magnitudes():
     for arch, (lo, hi) in expect.items():
         n = get_config(arch).param_count()
         assert lo <= n <= hi, f"{arch}: {n/1e9:.2f}B outside [{lo},{hi}]"
+
+
+def test_peak_table_is_keyed_by_device_kind_and_names_its_source():
+    from repro.launch.mesh import V5E, peak_rates
+    v5e = peak_rates(V5E)
+    assert (v5e.flops_bf16, v5e.hbm_bw) == (197e12, 819e9)
+    assert '"TPU v5e"' in v5e.source
+
+
+@pytest.mark.parametrize("kind", ["cpu", "TPU v4", ""])
+def test_peak_table_raises_for_an_unknown_device_kind(kind):
+    from repro.launch.mesh import peak_rates
+    with pytest.raises(ValueError, match="no published peaks"):
+        peak_rates(kind)

@@ -63,6 +63,37 @@ def test_parallel_falls_back_on_unpicklable_cells():
     assert "sequential" in msg
 
 
+@pytest.mark.parametrize("shared_instance", [False, True])
+def test_jax_data_plane_cells_run_in_process(monkeypatch, shared_instance):
+    """Only one process can hold a chip: cells whose backend is a JAX data
+    plane (named or an instance) run in the calling process with a
+    warning, and no process pool is started."""
+    import multiprocessing
+    from repro.core import ClusterConfig, JaxBackend
+    from repro.serving import smoke_apps
+
+    def no_pool(*a, **kw):
+        raise AssertionError("a JAX data-plane cell reached a process pool")
+
+    monkeypatch.setattr(multiprocessing, "get_context", no_pool)
+    base = Experiment(
+        backend=JaxBackend() if shared_instance else "jax",
+        workload_factory="serving_apps",
+        workload_kwargs=dict(apps=smoke_apps(), duration=0.5, rps=4.0,
+                             prewarm_per_fn=1),
+        cluster=ClusterConfig(n_sgs=1, workers_per_sgs=2,
+                              cores_per_worker=2),
+        drain=30.0)
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        sweep = run_sweep(base, {"seed": [0, 1]}, workers=2)
+    assert [r["result"]["backend"] for r in sweep.rows] == ["jax", "jax"]
+    assert all(r["result"]["n_completed"] == r["result"]["n_requests"] > 0
+               for r in sweep.rows)
+    msgs = [str(w.message) for w in caught]
+    assert any("JAX data-plane" in m and "sequential" in m for m in msgs)
+
+
 def test_keep_sim_runs_sequentially_and_keeps_handles():
     base = _grid_base()
     with warnings.catch_warnings(record=True) as caught:
