@@ -24,6 +24,7 @@ from ..core.types import FunctionSpec, Invocation
 from ..models import (decode_step, decode_step_ragged, init_cache,
                       init_params, prefill)
 from ..models.config import ModelConfig
+from ..spans import span
 
 
 def batch_seed(inv_ids: Iterable[int]) -> int:
@@ -103,22 +104,26 @@ class ModelInstance:
         sm = self.served
         cfg = sm.cfg
         t0 = time.perf_counter()
-        key = jax.random.PRNGKey(seed)
-        tokens = jax.random.randint(key, (sm.batch, sm.prompt_len), 0,
-                                    cfg.vocab_size)
-        if cfg.frontend:
-            frontend = jnp.zeros((sm.batch, cfg.n_frontend_tokens,
-                                  cfg.d_model), cfg.dtype())
-            logits, cache = self.prefill_fn(self.params, tokens, self.cache0,
-                                            frontend)
-        else:
-            logits, cache = self.prefill_fn(self.params, tokens, self.cache0)
-        tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+        with span("executor.prefill"):
+            key = jax.random.PRNGKey(seed)
+            tokens = jax.random.randint(key, (sm.batch, sm.prompt_len), 0,
+                                        cfg.vocab_size)
+            if cfg.frontend:
+                frontend = jnp.zeros((sm.batch, cfg.n_frontend_tokens,
+                                      cfg.d_model), cfg.dtype())
+                logits, cache = self.prefill_fn(self.params, tokens,
+                                                self.cache0, frontend)
+            else:
+                logits, cache = self.prefill_fn(self.params, tokens,
+                                                self.cache0)
+            tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
         for i in range(sm.gen_len):
-            logits, cache = self.decode_fn(self.params, cache, tok,
-                                           jnp.int32(sm.prompt_len + i))
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        jax.block_until_ready(tok)
+            with span("executor.decode_step"):
+                logits, cache = self.decode_fn(self.params, cache, tok,
+                                               jnp.int32(sm.prompt_len + i))
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with span("executor.sync"):
+            jax.block_until_ready(tok)
         return time.perf_counter() - t0
 
 
@@ -242,9 +247,12 @@ class BatchingJaxExecutor:
         """Execute ``invs`` as ONE padded batch; returns measured wall
         seconds (the shared runtime of every member)."""
         bucket = pow2_bucket(len(invs))
-        inst = self.ensure_instance(fn_name, bucket)
-        self.n_executions += 1
-        return inst.run(seed=batch_seed(inv.inv_id for inv in invs))
+        ids = [inv.inv_id for inv in invs]
+        with span("executor.run_batch", bucket=bucket, n=len(invs),
+                  members=ids):
+            inst = self.ensure_instance(fn_name, bucket)
+            self.n_executions += 1
+            return inst.run(seed=batch_seed(ids))
 
 
 @dataclass

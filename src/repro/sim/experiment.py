@@ -46,6 +46,7 @@ from ..core.sgs import SGSConfig
 from ..core.stacks import (LB_DECISION_COST, SGS_DECISION_COST, Stack,
                            get_stack)
 from ..core.types import DagSpec, Request
+from ..spans import span
 from .engine import SimEnv
 from .metrics import Metrics, percentile
 from .traffic import TrafficSpec, apply_traffic
@@ -513,12 +514,13 @@ def simulate(exp: Experiment, *,
         if not multiprocessing.current_process().daemon:
             return simulate_sharded(exp)
     exp_spec, sim, stack, wall = _run_experiment(exp, hooks, timed_calls)
-    counters = stack.counters()
-    warm_hits = counters.get("warm_hits", 0)
-    sev = getattr(stack, "scaling_events", None)
-    scaling = sev() if callable(sev) else []
-    return _build_result(exp, exp_spec, sim, warm_hits, wall, scaling,
-                         n_hedges=counters.get("hedges", 0))
+    with span("simulate.summary"):
+        counters = stack.counters()
+        warm_hits = counters.get("warm_hits", 0)
+        sev = getattr(stack, "scaling_events", None)
+        scaling = sev() if callable(sev) else []
+        return _build_result(exp, exp_spec, sim, warm_hits, wall, scaling,
+                             n_hedges=counters.get("hedges", 0))
 
 
 def _run_experiment(exp: Experiment,
@@ -536,91 +538,94 @@ def _run_experiment(exp: Experiment,
     hook (serving prewarm — the §3 "initial DAG upload") runs after the
     stack is built but before any arrival fires.
     """
-    stack_cls = get_stack(exp.stack)
-    _validate_params(exp, stack_cls)
-    spec = exp.resolve_workload()
-    backend = resolve_backend(exp.backend, exp.backend_kwargs)
-    spec = backend.build(exp, spec)
-    env = SimEnv()
-    backend.bind(env)
-    stack: Stack = stack_cls()
-    stack.build(env, exp, spec, backend)
-    pre_pump = getattr(spec, "pre_pump", None)
-    if pre_pump is not None:
-        pre_pump(env, stack)
-    # snapshot data-plane counters so the reported view is this run's delta
-    # (a shared backend instance accumulates across sweep cells)
-    counters_before = dict(backend.counters())
+    with span("simulate.setup"):
+        stack_cls = get_stack(exp.stack)
+        _validate_params(exp, stack_cls)
+        spec = exp.resolve_workload()
+        backend = resolve_backend(exp.backend, exp.backend_kwargs)
+        spec = backend.build(exp, spec)
+        env = SimEnv()
+        backend.bind(env)
+        stack: Stack = stack_cls()
+        stack.build(env, exp, spec, backend)
+        pre_pump = getattr(spec, "pre_pump", None)
+        if pre_pump is not None:
+            pre_pump(env, stack)
+        # snapshot data-plane counters so the reported view is this run's delta
+        # (a shared backend instance accumulates across sweep cells)
+        counters_before = dict(backend.counters())
 
-    t0 = time.perf_counter()
-    times, dags, arr_np, idx_np, tenant_dags = _arrival_columns(
-        spec, exp.seed, exp.workload_method)
-    # flat metrics plane: arrival columns attach wholesale, schedulers
-    # record completions straight into column buffers and release the
-    # Request objects.  Stacks that cannot wire the completion hook (custom
-    # schedulers predating it) fall back to the legacy per-object list.
-    flat = Metrics.flat(arr_np, idx_np, tenant_dags)
-    attach = getattr(stack, "attach_metrics", None)
-    if attach is not None and attach(flat):
-        metrics = flat
-        pending = flat._cols.pending
-        requests = None
-    else:
-        metrics = Metrics()
-        pending = None
-        requests = metrics.requests
-    n = len(times)
-    submit = stack.submit
+        t0 = time.perf_counter()
+        times, dags, arr_np, idx_np, tenant_dags = _arrival_columns(
+            spec, exp.seed, exp.workload_method)
+        # flat metrics plane: arrival columns attach wholesale, schedulers
+        # record completions straight into column buffers and release the
+        # Request objects.  Stacks that cannot wire the completion hook (custom
+        # schedulers predating it) fall back to the legacy per-object list.
+        flat = Metrics.flat(arr_np, idx_np, tenant_dags)
+        attach = getattr(stack, "attach_metrics", None)
+        if attach is not None and attach(flat):
+            metrics = flat
+            pending = flat._cols.pending
+            requests = None
+        else:
+            metrics = Metrics()
+            pending = None
+            requests = metrics.requests
+        n = len(times)
+        submit = stack.submit
 
-    # arrival i fires exactly at times[i] (the event heap is driven by the
-    # same float), so the pump reads the clock off the trace instead of
-    # calling env.now() per arrival
-    if pending is not None:
-        def pump(i: int) -> None:
-            # fire arrival i, then lazily schedule arrival i+1: the event
-            # heap holds one pending arrival instead of the whole trace
-            now = times[i]
-            req = Request(dag=dags[i], arrival_time=now)
-            req.m_idx = i
-            pending[i] = req
-            submit(req, now)
-            i += 1
-            if i < n:
-                env.call_at(times[i], pump, i)
-    else:
-        def pump(i: int) -> None:
-            now = times[i]
-            req = Request(dag=dags[i], arrival_time=now)
-            requests.append(req)
-            submit(req, now)
-            i += 1
-            if i < n:
-                env.call_at(times[i], pump, i)
+        # arrival i fires exactly at times[i] (the event heap is driven by the
+        # same float), so the pump reads the clock off the trace instead of
+        # calling env.now() per arrival
+        if pending is not None:
+            def pump(i: int) -> None:
+                # fire arrival i, then lazily schedule arrival i+1: the event
+                # heap holds one pending arrival instead of the whole trace
+                now = times[i]
+                req = Request(dag=dags[i], arrival_time=now)
+                req.m_idx = i
+                pending[i] = req
+                submit(req, now)
+                i += 1
+                if i < n:
+                    env.call_at(times[i], pump, i)
+        else:
+            def pump(i: int) -> None:
+                now = times[i]
+                req = Request(dag=dags[i], arrival_time=now)
+                requests.append(req)
+                submit(req, now)
+                i += 1
+                if i < n:
+                    env.call_at(times[i], pump, i)
 
-    if n:
-        env.call_at(times[0], pump, 0)
-    stack.start_background()
-    horizon = spec.duration + exp.drain
-    for interval, fn in hooks:
-        env.every(interval, lambda fn=fn: fn(env, stack), until=horizon)
-    for t, fn in timed_calls:
-        env.call_at(t, fn, env, stack)
-    injector: Optional[FaultInjector] = None
-    if exp.faults is not None and exp.faults.events:
-        injector = FaultInjector(exp.faults)
-        injector.install(env, stack, horizon)
+        if n:
+            env.call_at(times[0], pump, 0)
+        stack.start_background()
+        horizon = spec.duration + exp.drain
+        for interval, fn in hooks:
+            env.every(interval, lambda fn=fn: fn(env, stack), until=horizon)
+        for t, fn in timed_calls:
+            env.call_at(t, fn, env, stack)
+        injector: Optional[FaultInjector] = None
+        if exp.faults is not None and exp.faults.events:
+            injector = FaultInjector(exp.faults)
+            injector.install(env, stack, horizon)
 
-    env.run_until(horizon)
-    stack.collect(metrics)
-    wall = time.perf_counter() - t0
+    with span("simulate.events"):
+        env.run_until(horizon)
+    with span("simulate.result"):
+        stack.collect(metrics)
+        wall = time.perf_counter() - t0
 
-    counters = {k: v - counters_before.get(k, 0)
-                for k, v in backend.counters().items()}
-    sim = SimResult(metrics=metrics, env=env,
-                    lbs=getattr(stack, "lbs", None),
-                    scheduler=getattr(stack, "scheduler", None),
-                    backend=backend, backend_counters=counters,
-                    injector=injector)
+        counters = {k: v - counters_before.get(k, 0)
+                    for k, v in backend.counters().items()}
+        sim = SimResult(metrics=metrics, env=env,
+                        lbs=getattr(stack, "lbs", None),
+                        scheduler=getattr(stack, "scheduler", None),
+                        backend=backend, backend_counters=counters,
+                        injector=injector)
     return spec, sim, stack, wall
 
 
