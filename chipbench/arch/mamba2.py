@@ -159,10 +159,12 @@ def model_flops(c: Dict[str, Any], prompt: int, gen: int) -> float:
 
 def kernel_work(c: Dict[str, Any], prompt: int, gen: int, bucket: int
                 ) -> Dict[str, tuple]:
-    """Per batch of ``bucket`` rows: kernel -> (FLOPs, bytes, calls)."""
-    from yardstick import ssd_cost
+    """Per batch of ``bucket`` rows: kernel -> (FLOPs, bytes per operand,
+    calls)."""
+    from yardstick import scaled, ssd_cost, ssd_parts
     di = c["expand"] * c["d_model"]
     H = di // c["headdim"]
-    f, b = ssd_cost(bucket, prompt, H, c["headdim"], c["d_state"])
+    args = (bucket, prompt, H, c["headdim"], c["d_state"])
     L = c["n_layer"]
-    return {"ssd_scan": (L * f, L * b, L)}
+    return {"ssd_scan": (L * ssd_cost(*args)[0], scaled(ssd_parts(*args), L),
+                         L)}

@@ -144,12 +144,18 @@ def model_flops(c: Dict[str, Any], prompt: int, gen: int) -> float:
 
 def kernel_work(c: Dict[str, Any], prompt: int, gen: int, bucket: int
                 ) -> Dict[str, tuple]:
-    """Per batch of ``bucket`` rows: kernel -> (FLOPs, bytes, calls)."""
-    from yardstick import attention_cost, decode_attention_cost
+    """Per batch of ``bucket`` rows: kernel -> (FLOPs, bytes per operand,
+    calls)."""
+    from yardstick import (attention_cost, attention_parts,
+                           decode_attention_cost, decode_attention_parts,
+                           scaled, summed)
     L, d, nh, nkv, hd, ff, V = _dims(c)
-    fa = attention_cost(bucket, prompt, nh, nkv, hd, causal=True)
-    dec = [decode_attention_cost(bucket, prompt + i + 1, nh, nkv, hd)
-           for i in range(gen)]
-    return {"flash_attention": (L * fa[0], L * fa[1], L),
-            "decode_attention": (L * sum(f for f, _ in dec),
-                                 L * sum(b for _, b in dec), L * gen)}
+    fa = attention_cost(bucket, prompt, nh, nkv, hd, causal=True)[0]
+    dec = sum(decode_attention_cost(bucket, prompt + i + 1, nh, nkv, hd)[0]
+              for i in range(gen))
+    dec_parts = summed([decode_attention_parts(bucket, prompt + i + 1, nh,
+                                               nkv, hd) for i in range(gen)])
+    return {"flash_attention": (
+                L * fa, scaled(attention_parts(bucket, prompt, nh, nkv, hd),
+                               L), L),
+            "decode_attention": (L * dec, scaled(dec_parts, L), L * gen)}

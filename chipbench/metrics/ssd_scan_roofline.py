@@ -1,9 +1,15 @@
-"""Share of its roofline the ssd_scan kernel reached in the traced part of the
-window: the least time the chip needs for the work of every call the
-traced batches made, counted from their shapes, over the kernel's device
-time."""
+"""Share of its roofline the ssd_scan kernel reached in the traced part of
+the window: the least time the chip needs for the work of every call the
+traced batches made, counted from their shapes and from the operands that
+cross HBM, over the device time of the kernel and of the ops that move
+those operands (``metrics._common.roofline``)."""
 from metrics._common import roofline
+
+# the custom call's operands, inputs then outputs, named by the parts of
+# the yardstick's cost (None: dt's two prefix sums, which it does not
+# count apart from dt)
+OPERANDS = (("x", "dt", None, None, "B", "C", "state"), ("y", "state_out"))
 
 
 def read(ctx):
-    return roofline(ctx, "ssd_scan")
+    return roofline(ctx, "ssd_scan", OPERANDS)

@@ -88,7 +88,9 @@ def run_cell(bundle, seed: int, seconds: float, trace: bool, t_start: float,
                          "count": len(devs),
                          "memory_peak_bytes": stats.get("peak_bytes_in_use")}}
     if trace:
+        t_read = time.perf_counter()
         tr = traces.read_xspace(str(TRACE_DIR), harness.SPAN_PREFIX)
+        t_read = time.perf_counter() - t_read
         segspans = [s for s in tr.host_spans if s.name == "segment"]
         lo, hi = segspans[0].start, segspans[-1].end
         busy = traces.busy_ns(tr.device_ops[0], lo, hi) * 1e-9
@@ -99,7 +101,13 @@ def run_cell(bundle, seed: int, seconds: float, trace: bool, t_start: float,
                    traffic=traffic, arch=arch,
                    peaks=yardstick.peaks(dev.device_kind), trace=tr,
                    trace_lo=lo, trace_hi=hi, info=info)
+        t_readers = time.perf_counter()
         result["metrics"] = per_layer(bundle, ctx)
+        t_readers = time.perf_counter() - t_readers
+        info.append(f"trace: stopped {stopping!r} s, read {t_read!r} s "
+                    f"(module names {tr.hlo.scan_s!r} s), per-layer "
+                    f"readers {t_readers!r} s (text of {tr.hlo.made()} "
+                    f"of {len(tr.hlo)} modules {tr.hlo.text_s!r} s)")
         result["breakdown"] = {
             "device_ops": traces.top_ops(tr, lo, hi),
             "idle_gaps": traces.longest_gaps(tr, lo, hi)}
