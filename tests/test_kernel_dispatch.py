@@ -33,6 +33,9 @@ def _ssm(**kw):
 
 
 CFGS = [_dense(), _dense(sliding_window=4, name="t-swa"), _ssm()]
+# hd=8 against the tests' 12-slot caches: the decode kernel reads them with
+# the cache length on lanes (``t-dense``'s hd=16 keeps the head dim there)
+LANES = _dense(n_heads=8, name="t-lanes")
 
 
 @pytest.mark.parametrize("cfg", CFGS, ids=lambda c: c.name)
@@ -46,7 +49,7 @@ def test_forward_parity_xla_vs_pallas_interpret(cfg):
                                rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("cfg", CFGS, ids=lambda c: c.name)
+@pytest.mark.parametrize("cfg", CFGS + [LANES], ids=lambda c: c.name)
 def test_prefill_decode_parity_xla_vs_pallas_interpret(cfg):
     p = init_params(cfg, KEY)
     S = 64 if cfg.arch_type == "ssm" else 8
@@ -63,7 +66,7 @@ def test_prefill_decode_parity_xla_vs_pallas_interpret(cfg):
                                    rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("cfg", CFGS, ids=lambda c: c.name)
+@pytest.mark.parametrize("cfg", CFGS + [LANES], ids=lambda c: c.name)
 @pytest.mark.parametrize("kern", ["xla", "pallas_interpret"])
 def test_ragged_uniform_t_matches_decode_step(cfg, kern):
     """decode_step_ragged with a uniform position vector IS decode_step."""
@@ -82,8 +85,8 @@ def test_ragged_uniform_t_matches_decode_step(cfg, kern):
                                    np.asarray(b, np.float32), atol=0)
 
 
-@pytest.mark.parametrize("cfg", [_dense(), _dense(sliding_window=4)],
-                         ids=["full", "windowed"])
+@pytest.mark.parametrize("cfg", [_dense(), _dense(sliding_window=4), LANES],
+                         ids=["full", "windowed", "lanes"])
 @pytest.mark.parametrize("kern", ["xla", "pallas_interpret"])
 def test_ragged_rows_match_independent_sequences(cfg, kern):
     """A ragged batch at different depths must compute, row for row, what

@@ -6,7 +6,8 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops
-from repro.kernels.decode_attention import decode_attention
+from repro.kernels.decode_attention import (cache_len_on_lanes,
+                                            decode_attention)
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ref import (decode_attention_ref, flash_attention_ref,
                                ssd_scan_ref, ssd_scan_sequential_ref)
@@ -60,14 +61,23 @@ DEC_CASES = [
     # edge shapes (PR 9): cache smaller than one block, MHA limit
     (2, 33, 4, 2, 64, 128),                # L < block_k, ragged
     (1, 64, 1, 1, 32, 64),                 # single-head MHA
+    # phi3's serving shape (cache length on lanes), blocks derived
+    (1, 544, 32, 32, 96, None),
+    (2, 544, 32, 32, 96, None),
+    # hd=128 GQA (head dim on lanes), L not a multiple of the block
+    (2, 300, 8, 2, 128, 128),
 ]
 
 
-@pytest.mark.parametrize("fill", ["one", "full"])
-def test_decode_attention_valid_len_extremes(fill):
+@pytest.mark.parametrize("fill,hd", [("one", 64), ("full", 64),
+                                     ("one", 128), ("full", 128)],
+                         ids=["one", "full", "one-hd128", "full-hd128"])
+def test_decode_attention_valid_len_extremes(fill, hd):
     """valid_len at both ends of the legal range: 1 (only the first cache
-    slot attends) and L (the whole cache attends)."""
-    B, L, Hq, Hkv, hd = 2, 128, 4, 2, 64
+    slot attends) and L (the whole cache attends), in both orientations:
+    the cache length on lanes (hd=64) and the head dim on lanes (hd=128)."""
+    B, L, Hq, Hkv = 2, 128, 4, 2
+    assert cache_len_on_lanes(L, hd) == (hd == 64)
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (B, Hq, hd))
     k = jax.random.normal(ks[1], (B, L, Hkv, hd))
