@@ -1,4 +1,5 @@
-"""Architecture registry: the 10 assigned architectures (+ reduced variants).
+"""Architecture registry: the 10 assigned architectures and granite-4.0-h
+(+ reduced variants).
 
 Every entry cites its source in the module docstring of its config file.
 """
@@ -7,9 +8,9 @@ from __future__ import annotations
 from typing import Dict, Tuple
 
 from ..models.config import ModelConfig
-from . import (gemma3_1b, llama4_scout_17b, mamba2_370m, minicpm_2b,
-               minitron_8b, mixtral_8x22b, phi3_mini_3p8b, phi3_vision_4p2b,
-               whisper_tiny, zamba2_1p2b)
+from . import (gemma3_1b, granite4_h_small, llama4_scout_17b, mamba2_370m,
+               minicpm_2b, minitron_8b, mixtral_8x22b, phi3_mini_3p8b,
+               phi3_vision_4p2b, whisper_tiny, zamba2_1p2b)
 
 _MODULES = {
     "minicpm-2b": minicpm_2b,
@@ -22,6 +23,7 @@ _MODULES = {
     "llama4-scout-17b-a16e": llama4_scout_17b,
     "mamba2-370m": mamba2_370m,
     "mixtral-8x22b": mixtral_8x22b,
+    "granite-4.0-h-small": granite4_h_small,
 }
 
 ARCH_IDS = tuple(_MODULES)

@@ -129,20 +129,24 @@ def _dec_kernel(vlen_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
                       jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "block_k",
+                                             "interpret"))
 def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      valid_len: jnp.ndarray, *,
+                     scale: Optional[float] = None,
                      block_k: Optional[int] = None,
                      interpret: bool = False) -> jnp.ndarray:
     """q: (B,Hq,hd); k/v: (B,L,Hkv,hd); valid_len: (B,) -> (B,Hq,hd).
 
-    Scores are scaled by 1/sqrt(hd); cache uses prefix layout (slots
-    [0, valid_len) hold keys).  ``block_k`` overrides the cache slots per
-    block, which are otherwise sized from the shapes (``_blocks``)."""
+    Scores are scaled by ``scale`` (default 1/sqrt(hd)); cache uses prefix
+    layout (slots [0, valid_len) hold keys).  ``block_k`` overrides the
+    cache slots per block, which are otherwise sized from the shapes
+    (``_blocks``)."""
     B, Hq, hd = q.shape
     L, Hkv = k.shape[1], k.shape[2]
     G = Hq // Hkv
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
     lanes = cache_len_on_lanes(L, hd)
     hb, bk = _blocks(L, Hkv, hd, k.dtype.itemsize, lanes, block_k)
     nk = pl.cdiv(L, bk)

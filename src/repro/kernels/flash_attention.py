@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -93,20 +94,24 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
-                                             "block_k", "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal", "window", "scale",
+                                             "block_q", "block_k",
+                                             "interpret"))
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, window: int = 0,
+                    scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
                     interpret: bool = False) -> jnp.ndarray:
     """q: (B,Sq,Hq,hd), k/v: (B,Sk,Hkv,hd) -> (B,Sq,Hq,hd).
 
-    Queries are aligned to the end of the K sequence (decode-suffix
-    convention, matching ``ref.flash_attention_ref``)."""
+    Scores are scaled by ``scale`` (default 1/sqrt(hd)).  Queries are
+    aligned to the end of the K sequence (decode-suffix convention,
+    matching ``ref.flash_attention_ref``)."""
     B, Sq, Hq, hd = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
     G = Hq // Hkv
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
 
     bq = min(block_q, Sq)
     bk = min(block_k, Sk)

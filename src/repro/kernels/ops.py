@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from . import ref
 from .decode_attention import decode_attention as _dec_pallas
 from .flash_attention import flash_attention as _fa_pallas
+from .moe_gmm import moe_gmm as _gmm_pallas
 from .ssd_scan import ssd_scan as _ssd_pallas
 
 
@@ -76,6 +77,11 @@ KERNEL_TABLE = {
         KernelType.PALLAS: "decode_attention (compiled)",
         KernelType.PALLAS_INTERPRET: "decode_attention (interpret)",
     },
+    "moe_gmm": {
+        KernelType.XLA: "ref.moe_gmm_ref",
+        KernelType.PALLAS: "moe_gmm (compiled)",
+        KernelType.PALLAS_INTERPRET: "moe_gmm (interpret)",
+    },
     "ssd": {
         KernelType.XLA: "ref.ssd_scan_ref",
         KernelType.PALLAS: "ssd_scan (compiled)",
@@ -90,21 +96,34 @@ KERNEL_TABLE = {
 
 
 def attention(q, k, v, *, causal: bool = True, window: int = 0,
+              scale: Optional[float] = None,
               backend: Union[str, KernelType, None] = None) -> jnp.ndarray:
     b = normalize(backend)
     if b is KernelType.XLA:
-        return ref.flash_attention_ref(q, k, v, causal=causal, window=window)
-    return _fa_pallas(q, k, v, causal=causal, window=window,
+        return ref.flash_attention_ref(q, k, v, causal=causal, window=window,
+                                       scale=scale)
+    return _fa_pallas(q, k, v, causal=causal, window=window, scale=scale,
                       interpret=(b is KernelType.PALLAS_INTERPRET))
 
 
-def decode_attention(q, k, v, valid_len, *,
+def decode_attention(q, k, v, valid_len, *, scale: Optional[float] = None,
                      backend: Union[str, KernelType, None] = None
                      ) -> jnp.ndarray:
     b = normalize(backend)
     if b is KernelType.XLA:
-        return ref.decode_attention_ref(q, k, v, valid_len)
-    return _dec_pallas(q, k, v, valid_len,
+        return ref.decode_attention_ref(q, k, v, valid_len, scale=scale)
+    return _dec_pallas(q, k, v, valid_len, scale=scale,
+                       interpret=(b is KernelType.PALLAS_INTERPRET))
+
+
+def moe_gmm(x, w_gate, w_up, w_down, group_sizes, layer=None, *,
+            backend: Union[str, KernelType, None] = None) -> jnp.ndarray:
+    """SwiGLU expert FFN over rows sorted by expert (``moe_gmm.py``);
+    ``layer`` indexes stacked weights."""
+    b = normalize(backend)
+    if b is KernelType.XLA:
+        return ref.moe_gmm_ref(x, w_gate, w_up, w_down, group_sizes, layer)
+    return _gmm_pallas(x, w_gate, w_up, w_down, group_sizes, layer,
                        interpret=(b is KernelType.PALLAS_INTERPRET))
 
 

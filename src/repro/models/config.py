@@ -10,7 +10,9 @@ attention every-k) are expressed as several groups.  Group order is the
 execution order; for interleaved patterns we execute group-by-group, which
 permutes layers relative to the original checkpoints.  FLOPs / memory /
 collectives — everything the dry-run and roofline measure — are invariant
-under this permutation (see DESIGN.md §4).
+under this permutation (see DESIGN.md §4).  A config that gives its
+per-layer pattern (``layer_types``, granite-4.0-h) is executed in the
+published order instead: each run of equal kind is one group.
 """
 from __future__ import annotations
 
@@ -50,9 +52,13 @@ class ModelConfig:
     local_ratio: int = 0        # local layers per global layer (5 for gemma3)
 
     # -- MoE -----------------------------------------------------------------
-    n_experts: int = 0
+    n_experts: int = 0          # the router's width: experts published
     experts_per_token: int = 0
+    # capacity for the training path's dispatch; 0 = dropless everywhere.
+    # Serving (prefill / decode) is always dropless.
     capacity_factor: float = 1.25
+    experts_held: int = 0       # experts 0..held-1 live here; 0 = all
+    d_shared: int = 0           # shared SwiGLU expert's width; 0 = none
 
     # -- SSM (mamba2 / zamba2) ----------------------------------------------
     ssm_state: int = 0
@@ -61,6 +67,17 @@ class ModelConfig:
     ssm_chunk: int = 64
     ssm_conv: int = 4
     ssm_expand: int = 2
+    ssm_norm_eps: float = 1e-6  # the gated RMSNorm's eps
+
+    # -- per-layer mixer pattern ("mamba" | "attention"), published order ----
+    layer_types: Tuple[str, ...] = ()
+
+    # -- muP multipliers (granite): 0 / 1 keep the plain transformer ---------
+    embedding_multiplier: float = 0.0   # 0 -> sqrt(d_model)
+    residual_multiplier: float = 1.0    # each sublayer's output is scaled
+    attention_multiplier: float = 0.0   # score scale; 0 -> 1/sqrt(hd)
+    logits_scaling: float = 1.0         # logits are divided by it
+    rope: bool = True                   # False: no position encoding
 
     # -- hybrid (zamba2): one shared attention block every k mamba layers ----
     shared_attn_every: int = 0
@@ -121,6 +138,16 @@ class ModelConfig:
     def d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
+    @property
+    def n_held(self) -> int:
+        """Experts whose weights this chip holds (the first ``n_held``)."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def score_scale(self) -> Optional[float]:
+        """Attention's score scale; None for the kernels' 1/sqrt(hd)."""
+        return self.attention_multiplier or None
+
     def dtype(self) -> jnp.dtype:
         return jnp.dtype(self.compute_dtype)
 
@@ -133,6 +160,18 @@ class ModelConfig:
         if self.override_groups is not None:
             return self.override_groups
         moe = self.n_experts > 0
+        if self.layer_types:
+            if len(self.layer_types) != self.n_layers:
+                raise ValueError(f"{self.name}: {len(self.layer_types)} "
+                                 f"layer types for {self.n_layers} layers")
+            gs = []
+            for t in self.layer_types:
+                kind = {"mamba": "mamba", "attention": "attn"}[t]
+                if gs and gs[-1].kind == kind:
+                    gs[-1] = replace(gs[-1], count=gs[-1].count + 1)
+                else:
+                    gs.append(LayerGroup(kind, 1, moe=moe))
+            return tuple(gs)
         if self.arch_type == "ssm":
             return (LayerGroup("mamba", self.n_layers),)
         if self.arch_type == "hybrid":
@@ -185,6 +224,9 @@ class ModelConfig:
         total = v * d                      # embedding
         if not self.tie_embeddings:
             total += v * d
+        # held experts, router, shared expert (SwiGLU)
+        moe = (self.n_held * 3 * d * self.d_ff + d * self.n_experts
+               + 3 * d * self.d_shared)
         for g in self.groups():
             for _ in range(g.count):
                 if g.kind in ("attn", "shared_attn"):
@@ -198,7 +240,7 @@ class ModelConfig:
                         else d * self.d_ff
                     ff = ff_in + self.d_ff * d
                     if g.moe:
-                        total += self.n_experts * ff + d * self.n_experts
+                        total += moe
                     else:
                         total += ff
                     total += 2 * d        # norms
@@ -209,6 +251,8 @@ class ModelConfig:
                     total += di * d                      # out_proj
                     total += 3 * nh                      # A, dt_bias, D
                     total += d                           # norm
+                    if g.moe:
+                        total += moe + d                 # FFN and its norm
         # encoder stack
         if self.n_enc_layers:
             qkv = 4 * d * (self.n_heads * self.hd)
@@ -223,6 +267,8 @@ class ModelConfig:
         d = self.d_model
         ff_in = 2 * d * self.d_ff if self.mlp == "swiglu" else d * self.d_ff
         ff = ff_in + self.d_ff * d
-        dead_experts = self.n_experts - self.experts_per_token
+        # held experts a token picks on average, under uniform routing
+        picked = self.experts_per_token * self.n_held / self.n_experts
+        dead_experts = self.n_held - picked
         n_moe_layers = sum(g.count for g in self.groups() if g.moe)
-        return self.param_count() - n_moe_layers * dead_experts * ff
+        return int(self.param_count() - n_moe_layers * dead_experts * ff)

@@ -77,15 +77,17 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
 
 
 def gqa_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                  mask: Optional[jnp.ndarray]) -> jnp.ndarray:
+                  mask: Optional[jnp.ndarray],
+                  scale: Optional[float] = None) -> jnp.ndarray:
     """q: (B,Sq,Hq,hd)  k,v: (B,Sk,Hkv,hd)  mask: broadcastable to
-    (B,Hkv,G,Sq,Sk) or (B,1,1,Sq,Sk).  Returns (B,Sq,Hq,hd)."""
+    (B,Hkv,G,Sq,Sk) or (B,1,1,Sq,Sk).  Scores are scaled by ``scale``
+    (default 1/sqrt(hd)).  Returns (B,Sq,Hq,hd)."""
     B, Sq, Hq, hd = q.shape
     Hkv = k.shape[2]
     G = Hq // Hkv
     qg = q.reshape(B, Sq, Hkv, G, hd)
-    scores = jnp.einsum("bqkgd,bskd->bkgqs", qg.astype(f32),
-                        k.astype(f32)) / math.sqrt(hd)
+    scores = jnp.einsum("bqkgd,bskd->bkgqs", qg.astype(f32), k.astype(f32))
+    scores = scores / math.sqrt(hd) if scale is None else scores * scale
     if mask is not None:
         scores = jnp.where(mask, scores, -1e30)
     w = jax.nn.softmax(scores, axis=-1)
@@ -109,12 +111,14 @@ def attention_block(x: jnp.ndarray, p: Params, *, n_heads: int,
                     mask: Optional[jnp.ndarray], rope_theta: float,
                     kv_override: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
                     kernel: str = "xla", causal: bool = True, window: int = 0,
+                    rope: bool = True, scale: Optional[float] = None,
                     ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Self- (or cross-) attention sublayer body (no residual / norm).
 
     Returns (out, k, v) so callers can stash K/V into a cache.
     ``kv_override`` supplies externally computed K/V (cross-attention or a
-    decode-time cache).
+    decode-time cache).  ``rope=False`` applies no position encoding;
+    ``scale`` is the score scale (default 1/sqrt(hd)).
 
     ``kernel`` selects the attention implementation (``repro.kernels.ops``):
     the default ``"xla"`` applies the caller-built dense ``mask`` via the
@@ -126,16 +130,17 @@ def attention_block(x: jnp.ndarray, p: Params, *, n_heads: int,
     if kv_override is None:
         k = (x @ p["wk"]).reshape(B, S, n_kv_heads, hd)
         v = (x @ p["wv"]).reshape(B, S, n_kv_heads, hd)
-        q = apply_rope(q, positions, rope_theta)
-        k = apply_rope(k, positions, rope_theta)
     else:
         k, v = kv_override
+    if rope:
         q = apply_rope(q, positions, rope_theta)
+        if kv_override is None:
+            k = apply_rope(k, positions, rope_theta)
     if kernel != "xla":
         out = kernel_ops.attention(q, k, v, causal=causal, window=window,
-                                   backend=kernel)
+                                   scale=scale, backend=kernel)
     else:
-        out = gqa_attention(q, k, v, mask)
+        out = gqa_attention(q, k, v, mask, scale)
     out = out.reshape(B, S, n_heads * hd) @ p["wo"]
     return out, k, v
 
@@ -204,6 +209,54 @@ def moe_block(x: jnp.ndarray, p: Params, *, n_experts: int, k: int,
     ce = one_hot_top1.mean(axis=0)
     aux = E * jnp.sum(me * ce)
     return y.reshape(B, S, d), aux
+
+
+def moe_dropless(x: jnp.ndarray, p: Params, *, k: int, first: int = 0,
+                 kernel: str = "xla", layer: Optional[jnp.ndarray] = None,
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Dropless routed experts over the experts this chip holds.
+
+    x: (B,S,d) -> (y (B,S,d), counts int32[3]).  The router (``router``,
+    (d, E)) scores all E experts in float32; each token takes its top
+    ``k`` and weights them by a softmax over those ``k`` logits.  Of the
+    T*k assignments, those that fall on held experts (``first`` to
+    ``first + G - 1``, the G of ``w_gate``/``w_up``/``w_down``, stacked
+    over layers where ``layer`` indexes them) are sorted
+    by expert and run through ``kernel_ops.moe_gmm`` (the SwiGLU FFN of
+    each expert over its rows); each result is multiplied by its gate and
+    summed back into its token.  The row buffer holds every assignment, so
+    no token is dropped at any imbalance, and an expert with no rows is
+    not computed.  Assignments to experts held elsewhere add nothing here.
+
+    ``counts``: rows that fell on held experts, held experts with at least
+    one row (the weight fetches), and held assignments left uncomputed
+    (always 0: the check that the dispatch dropped nothing)."""
+    B, S, d = x.shape
+    T = B * S
+    G = p["w_gate"].shape[-3]
+    xt = x.reshape(T, d)
+    logits = jnp.dot(xt, p["router"], preferred_element_type=f32)   # (T,E)
+    top, eidx = jax.lax.top_k(logits, k)                            # (T,k)
+    gates = jax.nn.softmax(top, axis=-1)
+    held = (eidx >= first) & (eidx < first + G)
+    key = jnp.where(held, eidx - first, G).reshape(-1)              # (T*k,)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.zeros((G + 1,), jnp.int32).at[key].add(1)[:G]
+    rows = kernel_ops.moe_gmm(xt[order // k], p["w_gate"], p["w_up"],
+                              p["w_down"], sizes, layer, backend=kernel)
+    # back to (token, choice): the row of each assignment, masked where
+    # the expert is held elsewhere (those rows were not computed)
+    slot = jnp.zeros_like(order).at[order].set(
+        jnp.arange(T * k, dtype=order.dtype)).reshape(T, k)
+    y = jnp.zeros((T, d), f32)
+    for j in range(k):          # one (T, d) gather per choice
+        y = y + jnp.where(held[:, j, None],
+                          rows[slot[:, j]].astype(f32) * gates[:, j, None],
+                          0.0)
+    n_held = jnp.sum(held, dtype=jnp.int32)
+    counts = jnp.stack([n_held, jnp.sum(sizes > 0, dtype=jnp.int32),
+                        n_held - jnp.sum(sizes)])
+    return y.astype(x.dtype).reshape(B, S, d), counts
 
 
 # ---------------------------------------------------------------------------
@@ -306,7 +359,7 @@ def ssd_decode_step(state: jnp.ndarray, x: jnp.ndarray, dt: jnp.ndarray,
 def mamba2_block(x: jnp.ndarray, p: Params, *, n_heads: int, head_dim: int,
                  d_state: int, d_conv: int, chunk: int,
                  cache: Optional[Dict] = None, unroll: bool = False,
-                 kernel: str = "xla",
+                 kernel: str = "xla", norm_eps: float = 1e-6,
                  ) -> Tuple[jnp.ndarray, Optional[Dict]]:
     """Full Mamba2 mixer (in_proj -> conv -> SSD -> gated norm -> out_proj).
 
@@ -353,7 +406,7 @@ def mamba2_block(x: jnp.ndarray, p: Params, *, n_heads: int, head_dim: int,
     y = y + xh * p["D"][None, None, :, None].astype(xh.dtype)
     y = y.reshape(B, S, di)
     # gated RMSNorm (mamba2 style): norm(y * silu(z))
-    y = rms_norm(y * jax.nn.silu(z), p["norm_w"])
+    y = rms_norm(y * jax.nn.silu(z), p["norm_w"], norm_eps)
     out = y @ p["out_proj"]
     new_cache = None
     if cache is not None or True:

@@ -29,11 +29,16 @@ import jax.numpy as jnp
 from ..kernels import ops as kernel_ops
 from .config import LayerGroup, ModelConfig
 from .layers import (attention_block, causal_window_mask, gqa_attention,
-                     gelu_mlp, mamba2_block, moe_block, rms_norm, swiglu,
-                     apply_rope)
+                     gelu_mlp, mamba2_block, moe_block, moe_dropless,
+                     rms_norm, swiglu, apply_rope)
 
 Params = Dict[str, Any]
 f32 = jnp.float32
+# the expert layer's counters per call (``layers.moe_dropless``): rows on
+# held experts, held experts with rows, held rows left uncomputed.  A MoE
+# model's cache carries them summed over prefill (row 0) and over decode
+# steps (row 1) under "moe".
+MOE_COUNTS = ("rows", "fetches", "dropped")
 
 
 # ---------------------------------------------------------------------------
@@ -61,9 +66,7 @@ def _attn_layer_shapes(cfg: ModelConfig, g: LayerGroup) -> Dict[str, tuple]:
         s.update({"ln_x": (d,), "xwq": (d, hq * hd), "xwk": (d, hkv * hd),
                   "xwv": (d, hkv * hd), "xwo": (hq * hd, d)})
     if g.moe:
-        E = cfg.n_experts
-        s.update({"router": (d, E), "w_gate": (E, d, f), "w_up": (E, d, f),
-                  "w_down": (E, f, d)})
+        s.update(_moe_shapes(cfg))
     elif cfg.mlp == "swiglu":
         s.update({"w_gate": (d, f), "w_up": (d, f), "w_down": (f, d)})
     else:
@@ -71,17 +74,32 @@ def _attn_layer_shapes(cfg: ModelConfig, g: LayerGroup) -> Dict[str, tuple]:
     return s
 
 
-def _mamba_layer_shapes(cfg: ModelConfig) -> Dict[str, tuple]:
+def _moe_shapes(cfg: ModelConfig) -> Dict[str, tuple]:
+    """The router over every expert, the held experts, the shared one."""
+    d, E, f = cfg.d_model, cfg.n_held, cfg.d_ff
+    s = {"router": (d, cfg.n_experts), "w_gate": (E, d, f),
+         "w_up": (E, d, f), "w_down": (E, f, d)}
+    if cfg.d_shared:
+        fs = cfg.d_shared
+        s.update({"shared_gate": (d, fs), "shared_up": (d, fs),
+                  "shared_down": (fs, d)})
+    return s
+
+
+def _mamba_layer_shapes(cfg: ModelConfig, g: LayerGroup) -> Dict[str, tuple]:
     d, di, N = cfg.d_model, cfg.d_inner, cfg.ssm_state
     H = cfg.n_ssm_heads
     dxbc = di + 2 * N
-    return {
+    s = {
         "ln": (d,),
         "in_proj": (d, 2 * di + 2 * N + H),
         "conv_w": (cfg.ssm_conv, dxbc), "conv_b": (dxbc,),
         "dt_bias": (H,), "A_log": (H,), "D": (H,),
         "norm_w": (di,), "out_proj": (di, d),
     }
+    if g.moe:
+        s.update(_moe_shapes(cfg), ln2=(d,))
+    return s
 
 
 def _init_layer(key, shapes: Dict[str, tuple], count: int, dtype) -> Params:
@@ -128,7 +146,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
                 shared_done = True
             glist.append({})        # placeholder; uses params["shared_attn"]
         elif g.kind == "mamba":
-            glist.append(_init_layer(gk, _mamba_layer_shapes(cfg),
+            glist.append(_init_layer(gk, _mamba_layer_shapes(cfg, g),
                                      g.count, dtype))
         else:
             glist.append(_init_layer(gk, _attn_layer_shapes(cfg, g),
@@ -149,32 +167,94 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
 # ---------------------------------------------------------------------------
 
 
-def _ffn(cfg: ModelConfig, g: LayerGroup, p: Params,
-         x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    if g.moe:
-        return moe_block(x, p, n_experts=cfg.n_experts,
-                         k=cfg.experts_per_token,
-                         capacity_factor=cfg.capacity_factor, mlp=cfg.mlp)
-    if cfg.mlp == "swiglu":
-        return swiglu(x, p["w_gate"], p["w_up"], p["w_down"]), jnp.zeros((), f32)
-    return gelu_mlp(x, p["w_up"], p["w_down"]), jnp.zeros((), f32)
+def _ffn(cfg: ModelConfig, g: LayerGroup, p: Params, x: jnp.ndarray,
+         serving: bool = False):
+    """(y, aux loss, counts): the layer's FFN.  A MoE layer serves
+    (``serving``: prefill and decode) through the dropless expert layer,
+    with its counters; training takes the capacity dispatch unless the
+    config has no capacity.  Counts are None for a dense FFN."""
+    zero = jnp.zeros((), f32)
+    if not g.moe:
+        if cfg.mlp == "swiglu":
+            return swiglu(x, p["w_gate"], p["w_up"], p["w_down"]), zero, None
+        return gelu_mlp(x, p["w_up"], p["w_down"]), zero, None
+    with jax.named_scope("moe"):
+        if _dropless(cfg, serving):
+            y, counts = moe_dropless(x, p, k=cfg.experts_per_token,
+                                     kernel=cfg.kernels,
+                                     layer=p.get("layer"))
+            aux = zero
+        else:
+            y, aux = moe_block(x, p, n_experts=cfg.n_experts,
+                               k=cfg.experts_per_token,
+                               capacity_factor=cfg.capacity_factor,
+                               mlp=cfg.mlp)
+            counts = jnp.zeros((len(MOE_COUNTS),), jnp.int32)
+        if cfg.d_shared:
+            y = y + swiglu(x, p["shared_gate"], p["shared_up"],
+                           p["shared_down"])
+    return y, aux, counts
+
+
+def _res(cfg: ModelConfig, y: jnp.ndarray) -> jnp.ndarray:
+    """A sublayer's output as it joins the residual stream."""
+    m = cfg.residual_multiplier
+    return y if m == 1.0 else y * m
+
+
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+def _dropless(cfg: ModelConfig, serving: bool) -> bool:
+    """Whether a MoE layer takes the dropless expert layer."""
+    return serving or not cfg.capacity_factor
+
+
+def _layer_xs(cfg: ModelConfig, g: LayerGroup, gp: Params, serving: bool):
+    """What a stacked group's scan runs over, and how a step rebuilds its
+    layer's parameters from that.  A dropless MoE group keeps its experts
+    out of the scan: a step gets them stacked, with its layer's index, and
+    the expert kernel reads only the experts that have rows (a scan's
+    slice would copy every held expert of the layer, each step)."""
+    if not (g.moe and _is_stacked(gp) and _dropless(cfg, serving)):
+        return gp, lambda lp: lp
+    experts = {n: gp[n] for n in EXPERT_WEIGHTS}
+    rest = {n: v for n, v in gp.items() if n not in EXPERT_WEIGHTS}
+    return ((rest, jnp.arange(g.count, dtype=jnp.int32)),
+            lambda xs: dict(xs[0], layer=xs[1], **experts))
+
+
+def _counts0(g: LayerGroup):
+    return jnp.zeros((len(MOE_COUNTS),), jnp.int32) if g.moe else None
+
+
+def _count(total, counts):
+    """``total`` plus ``counts``; either is None where there are no
+    experts."""
+    if counts is None:
+        return total
+    return counts if total is None else total + counts
 
 
 def _attn_group_fwd(cfg: ModelConfig, g: LayerGroup, gp: Params,
                     x: jnp.ndarray, positions: jnp.ndarray,
                     mask: Optional[jnp.ndarray],
                     enc_out: Optional[jnp.ndarray],
-                    collect_kv: bool):
-    """Run a stacked attention group via scan.  Returns (x, aux, kv)."""
+                    collect_kv: bool, serving: bool = False):
+    """Run a stacked attention group via scan.  Returns (x, aux, kv,
+    expert counts)."""
+    xs, bind = _layer_xs(cfg, g, gp, serving)
 
     def body(carry, lp):
-        h, aux = carry
+        h, aux, cnt = carry
+        lp = bind(lp)
         a, k, v = attention_block(
             rms_norm(h, lp["ln1"], cfg.norm_eps), lp,
             n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads, hd=cfg.hd,
             positions=positions, mask=mask, rope_theta=cfg.rope_theta,
-            kernel=cfg.kernels, causal=True, window=g.window)
-        h = h + a
+            kernel=cfg.kernels, causal=True, window=g.window,
+            rope=cfg.rope, scale=cfg.score_scale)
+        h = h + _res(cfg, a)
         if g.cross_attn:
             xa, _, _ = attention_block(
                 rms_norm(h, lp["ln_x"], cfg.norm_eps),
@@ -185,20 +265,21 @@ def _attn_group_fwd(cfg: ModelConfig, g: LayerGroup, gp: Params,
                 kv_override=_enc_kv(cfg, lp, enc_out),
                 kernel=cfg.kernels, causal=False, window=0)
             h = h + xa
-        f, a_loss = _ffn(cfg, g, lp, rms_norm(h, lp["ln2"], cfg.norm_eps))
-        h = h + f
+        f, a_loss, c = _ffn(cfg, g, lp, rms_norm(h, lp["ln2"], cfg.norm_eps),
+                            serving)
+        h = h + _res(cfg, f)
         out = (k, v) if collect_kv else None
-        return (h, aux + a_loss), out
+        return (h, aux + a_loss, _count(cnt, c)), out
 
     if cfg.remat:
         body = jax.checkpoint(body)     # layer-boundary remat (training mem)
+    carry = (x, jnp.zeros((), f32), _counts0(g))
     if g.count == 1 and not _is_stacked(gp):
-        (x, aux), kv = body((x, jnp.zeros((), f32)), gp)
+        (x, aux, cnt), kv = body(carry, gp)
         kv = jax.tree.map(lambda t: t[None], kv) if kv is not None else None
-        return x, aux, kv
-    (x, aux), kv = jax.lax.scan(body, (x, jnp.zeros((), f32)), gp,
-                                unroll=cfg.scan_unroll)
-    return x, aux, kv
+        return x, aux, kv, cnt
+    (x, aux, cnt), kv = jax.lax.scan(body, carry, xs, unroll=cfg.scan_unroll)
+    return x, aux, kv, cnt
 
 
 def _is_stacked(gp: Params) -> bool:
@@ -213,33 +294,48 @@ def _enc_kv(cfg: ModelConfig, lp: Params, enc_out: jnp.ndarray):
     return k, v
 
 
-def _mamba_group_fwd(cfg: ModelConfig, gp: Params, x: jnp.ndarray,
-                     cache: Optional[Dict], collect_state: bool):
+def _mamba_group_fwd(cfg: ModelConfig, g: LayerGroup, gp: Params,
+                     x: jnp.ndarray, cache: Optional[Dict],
+                     collect_state: bool, serving: bool = False):
+    """Run a group of Mamba-2 layers (each with its FFN where the group
+    has experts).  Returns (x, new cache, expert counts)."""
+    layer_xs, bind = _layer_xs(cfg, g, gp, serving)
+
     def body(carry, inp):
-        h = carry
+        h, cnt = carry
         if cache is not None:
             lp, lc = inp
         else:
             lp, lc = inp, None
+        lp = bind(lp)
         y, new_c = mamba2_block(
             rms_norm(h, lp["ln"], cfg.norm_eps), lp,
             n_heads=cfg.n_ssm_heads, head_dim=cfg.ssm_head_dim,
             d_state=cfg.ssm_state, d_conv=cfg.ssm_conv, chunk=cfg.ssm_chunk,
-            cache=lc, kernel=cfg.kernels)
-        return h + y, (new_c if (collect_state or cache is not None) else None)
+            cache=lc, kernel=cfg.kernels, norm_eps=cfg.ssm_norm_eps)
+        h = h + _res(cfg, y)
+        if g.moe:
+            f, _, c = _ffn(cfg, g, lp, rms_norm(h, lp["ln2"], cfg.norm_eps),
+                           serving)
+            h = h + _res(cfg, f)
+            cnt = _count(cnt, c)
+        return (h, cnt), (new_c if (collect_state or cache is not None)
+                          else None)
 
     if cfg.remat:
         body = jax.checkpoint(body)
+    carry = (x, _counts0(g))
     if not _is_stacked(gp):
         lc0 = jax.tree.map(lambda a: a[0], cache) if cache is not None \
             else None
-        x, nc0 = body(x, (gp, lc0) if cache is not None else gp)
+        (x, cnt), nc0 = body(carry, (gp, lc0) if cache is not None else gp)
         if nc0 is not None:
             nc0 = jax.tree.map(lambda a: a[None], nc0)
-        return x, nc0
-    xs = (gp, cache) if cache is not None else gp
-    x, new_cache = jax.lax.scan(body, x, xs, unroll=cfg.scan_unroll)
-    return x, new_cache
+        return x, nc0, cnt
+    xs = (layer_xs, cache) if cache is not None else layer_xs
+    (x, cnt), new_cache = jax.lax.scan(body, carry, xs,
+                                       unroll=cfg.scan_unroll)
+    return x, new_cache, cnt
 
 
 # ---------------------------------------------------------------------------
@@ -247,10 +343,14 @@ def _mamba_group_fwd(cfg: ModelConfig, gp: Params, x: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 
+def _embed_scale(cfg: ModelConfig) -> float:
+    return cfg.embedding_multiplier or math.sqrt(cfg.d_model)
+
+
 def _embed(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
            frontend: Optional[jnp.ndarray]) -> jnp.ndarray:
     h = params["embed"][tokens].astype(cfg.dtype())
-    h = h * math.sqrt(cfg.d_model)
+    h = h * _embed_scale(cfg)
     if frontend is not None and cfg.frontend and cfg.arch_type != "encdec":
         h = jnp.concatenate([frontend.astype(cfg.dtype()), h], axis=1)
     return h
@@ -259,8 +359,12 @@ def _embed(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
 def _unembed(cfg: ModelConfig, params: Params, h: jnp.ndarray) -> jnp.ndarray:
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     if cfg.tie_embeddings:
-        return h @ params["embed"].T.astype(h.dtype)
-    return h @ params["lm_head"].astype(h.dtype)
+        logits = h @ params["embed"].T.astype(h.dtype)
+    else:
+        logits = h @ params["lm_head"].astype(h.dtype)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def _encode(cfg: ModelConfig, params: Params,
@@ -277,8 +381,8 @@ def _encode(cfg: ModelConfig, params: Params,
             positions=pos, mask=None, rope_theta=cfg.rope_theta,
             kernel=cfg.kernels, causal=False, window=0)
         hh = hh + a
-        f, _ = _ffn(cfg, LayerGroup("attn", 1), lp,
-                    rms_norm(hh, lp["ln2"], cfg.norm_eps))
+        f, _, _ = _ffn(cfg, LayerGroup("attn", 1), lp,
+                       rms_norm(hh, lp["ln2"], cfg.norm_eps))
         return hh + f, None
 
     h, _ = jax.lax.scan(body, h, params["encoder"], unroll=cfg.scan_unroll)
@@ -307,13 +411,14 @@ def forward(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     shared_idx = 0
     for g, gp in zip(cfg.groups(), params["groups"]):
         if g.kind == "mamba":
-            h, _ = _mamba_group_fwd(cfg, gp, h, None, collect_state=False)
+            h, _, _ = _mamba_group_fwd(cfg, g, gp, h, None,
+                                       collect_state=False)
         else:
             lp = params["shared_attn"] if g.kind == "shared_attn" else gp
             mask = causal_window_mask(positions[0], positions[0],
                                       g.window)[None, None, None]
-            h, a, _ = _attn_group_fwd(cfg, g, lp, h, positions, mask,
-                                      enc_out, collect_kv=False)
+            h, a, _, _ = _attn_group_fwd(cfg, g, lp, h, positions, mask,
+                                         enc_out, collect_kv=False)
             aux = aux + a
             if g.kind == "shared_attn":
                 shared_idx += 1
@@ -358,6 +463,9 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                 e["xv"] = jnp.zeros((g.count, batch, L, cfg.n_kv_heads,
                                      cfg.hd), dt)
             entries.append(e)
+    if any(g.moe for g in groups):
+        return {"layers": entries,
+                "moe": jnp.zeros((2, len(MOE_COUNTS)), jnp.int32)}
     return {"layers": entries}
 
 
@@ -378,21 +486,23 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     B, S, _ = h.shape
     positions = jnp.arange(S)[None, :]
     new_layers = []
+    counts = None
     for g, gp, ce in zip(cfg.groups(), params["groups"], cache["layers"]):
         if g.kind == "mamba":
-            zero = {"conv": jnp.zeros_like(ce["conv"][0]),
-                    "state": jnp.zeros_like(ce["state"][0])}
             stacked_zero = jax.tree.map(
                 lambda t: jnp.zeros_like(t), ce)
-            h, nc = _mamba_group_fwd(cfg, gp, h, stacked_zero,
-                                     collect_state=True)
+            h, nc, cnt = _mamba_group_fwd(cfg, g, gp, h, stacked_zero,
+                                          collect_state=True, serving=True)
+            counts = _count(counts, cnt)
             new_layers.append(nc)
         else:
             lp = params["shared_attn"] if g.kind == "shared_attn" else gp
             mask = causal_window_mask(positions[0], positions[0],
                                       g.window)[None, None, None]
-            h, _, kv = _attn_group_fwd(cfg, g, lp, h, positions, mask,
-                                       enc_out, collect_kv=True)
+            h, _, kv, cnt = _attn_group_fwd(cfg, g, lp, h, positions, mask,
+                                            enc_out, collect_kv=True,
+                                            serving=True)
+            counts = _count(counts, cnt)
             k, v = kv
             W = ce["k"].shape[2]
             e = {"k": _ring_fill(ce["k"], k, S, W),
@@ -410,7 +520,16 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
                     xv.astype(ce["xv"].dtype)
             new_layers.append(e)
     logits = _unembed(cfg, params, h[:, -1:, :])
-    return logits, {"layers": new_layers}
+    return logits, _with_counts(cache, new_layers, counts, 0)
+
+
+def _with_counts(cache: Dict[str, Any], layers: List, counts: jnp.ndarray,
+                 row: int) -> Dict[str, Any]:
+    """The new cache: ``layers``, and the expert counters with ``counts``
+    added to ``row`` (0 prefill, 1 decode) where the model has them."""
+    if "moe" not in cache:
+        return {"layers": layers}
+    return {"layers": layers, "moe": cache["moe"].at[row].add(counts)}
 
 
 def _ring_fill(dst: jnp.ndarray, kv: jnp.ndarray, S: int, W: int
@@ -455,18 +574,20 @@ def decode_step_ragged(cfg: ModelConfig, params: Params,
 def _decode_impl(cfg: ModelConfig, params: Params, cache: Dict[str, Any],
                  token: jnp.ndarray, positions: jnp.ndarray, t: jnp.ndarray,
                  ) -> Tuple[jnp.ndarray, Dict[str, Any]]:
-    h = params["embed"][token].astype(cfg.dtype()) * math.sqrt(cfg.d_model)
+    h = params["embed"][token].astype(cfg.dtype()) * _embed_scale(cfg)
     new_layers = []
+    counts = None
     for g, gp, ce in zip(cfg.groups(), params["groups"], cache["layers"]):
         if g.kind == "mamba":
-            h, nc = _mamba_group_fwd(cfg, gp, h, ce, collect_state=False)
-            new_layers.append(nc)
+            h, nc, cnt = _mamba_group_fwd(cfg, g, gp, h, ce,
+                                          collect_state=False, serving=True)
         else:
             lp = params["shared_attn"] if g.kind == "shared_attn" else gp
-            h, nc = _attn_group_decode(cfg, g, lp, ce, h, positions, t)
-            new_layers.append(nc)
+            h, nc, cnt = _attn_group_decode(cfg, g, lp, ce, h, positions, t)
+        counts = _count(counts, cnt)
+        new_layers.append(nc)
     logits = _unembed(cfg, params, h)
-    return logits, {"layers": new_layers}
+    return logits, _with_counts(cache, new_layers, counts, 1)
 
 
 def _attn_group_decode(cfg: ModelConfig, g: LayerGroup, gp: Params,
@@ -494,12 +615,14 @@ def _attn_group_decode(cfg: ModelConfig, g: LayerGroup, gp: Params,
     # sliding-window rings are not a prefix layout and stay on the masked
     # jnp reference (see docs/KERNELS.md)
     use_dec_kernel = cfg.kernels != "xla" and g.window <= 0
+    layer_xs, bind = _layer_xs(cfg, g, gp, serving=True)
 
     def body(carry, inp):
-        h = carry
+        h, cnt = carry
         lp = gp if not _is_stacked(gp) else None
         if lp is None:
             lp, lc = inp
+            lp = bind(lp)
         else:
             lc = inp
         hn = rms_norm(h, lp["ln1"], cfg.norm_eps)
@@ -507,8 +630,9 @@ def _attn_group_decode(cfg: ModelConfig, g: LayerGroup, gp: Params,
         q = (hn @ lp["wq"]).reshape(B, 1, cfg.n_heads, cfg.hd)
         k1 = (hn @ lp["wk"]).reshape(B, 1, cfg.n_kv_heads, cfg.hd)
         v1 = (hn @ lp["wv"]).reshape(B, 1, cfg.n_kv_heads, cfg.hd)
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k1 = apply_rope(k1, positions, cfg.rope_theta)
+        if cfg.rope:
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k1 = apply_rope(k1, positions, cfg.rope_theta)
         if ragged:
             rows = jnp.arange(B)
             nk = lc["k"].at[rows, slot].set(k1[:, 0].astype(lc["k"].dtype))
@@ -521,11 +645,12 @@ def _attn_group_decode(cfg: ModelConfig, g: LayerGroup, gp: Params,
         if use_dec_kernel:
             vlen = jnp.broadcast_to(t + 1, (B,)).astype(jnp.int32)
             a1 = kernel_ops.decode_attention(q[:, 0], nk, nv, vlen,
+                                             scale=cfg.score_scale,
                                              backend=cfg.kernels)
             a = a1[:, None]
         else:
-            a = gqa_attention(q, nk, nv, mask)
-        h = h + a.reshape(B, 1, cfg.n_heads * cfg.hd) @ lp["wo"]
+            a = gqa_attention(q, nk, nv, mask, cfg.score_scale)
+        h = h + _res(cfg, a.reshape(B, 1, cfg.n_heads * cfg.hd) @ lp["wo"])
         if g.cross_attn:
             hx = rms_norm(h, lp["ln_x"], cfg.norm_eps)
             qx = (hx @ lp["xwq"]).reshape(B, 1, cfg.n_heads, cfg.hd)
@@ -536,18 +661,21 @@ def _attn_group_decode(cfg: ModelConfig, g: LayerGroup, gp: Params,
             else:
                 ax = gqa_attention(qx, lc["xk"], lc["xv"], None)
             h = h + ax.reshape(B, 1, cfg.n_heads * cfg.hd) @ lp["xwo"]
-        f, _ = _ffn(cfg, g, lp, rms_norm(h, lp["ln2"], cfg.norm_eps))
-        h = h + f
+        f, _, c = _ffn(cfg, g, lp, rms_norm(h, lp["ln2"], cfg.norm_eps),
+                       serving=True)
+        h = h + _res(cfg, f)
         nc = dict(lc)
         nc["k"], nc["v"] = nk, nv
-        return h, nc
+        return (h, _count(cnt, c)), nc
 
+    carry = (x, _counts0(g))
     if not _is_stacked(gp):
         lc0 = jax.tree.map(lambda a: a[0], ce)
-        x, nc0 = body(x, lc0)
-        return x, jax.tree.map(lambda a: a[None], nc0)
-    x, nc = jax.lax.scan(body, x, (gp, ce), unroll=cfg.scan_unroll)
-    return x, nc
+        (x, cnt), nc0 = body(carry, lc0)
+        return x, jax.tree.map(lambda a: a[None], nc0), cnt
+    (x, cnt), nc = jax.lax.scan(body, carry, (layer_xs, ce),
+                                unroll=cfg.scan_unroll)
+    return x, nc, cnt
 
 
 # ---------------------------------------------------------------------------

@@ -24,7 +24,21 @@ from ..core.types import FunctionSpec, Invocation
 from ..models import (decode_step, decode_step_ragged, init_cache,
                       init_params, prefill)
 from ..models.config import ModelConfig
+from ..models.transformer import MOE_COUNTS
 from ..spans import span
+
+
+def moe_counts(cache: Any) -> Dict[str, int]:
+    """The expert layer's counters a request's final cache carries, summed
+    over its prefill and over its decode steps (``moe_prefill_rows``, ...,
+    ``moe_decode_dropped``); empty for a model without experts.  Reading
+    them waits for the device and copies to the host."""
+    if "moe" not in cache:
+        return {}
+    got = jax.device_get(cache["moe"])
+    return {f"moe_{phase}_{name}": int(got[i, j])
+            for i, phase in enumerate(("prefill", "decode"))
+            for j, name in enumerate(MOE_COUNTS)}
 
 
 def batch_seed(inv_ids: Iterable[int]) -> int:
@@ -122,8 +136,12 @@ class ModelInstance:
                 logits, cache = self.decode_fn(self.params, cache, tok,
                                                jnp.int32(sm.prompt_len + i))
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        with span("executor.sync"):
+        with span("executor.sync") as sp:
             jax.block_until_ready(tok)
+            if sp is not None:
+                # recorded only while profiling: the counters become the
+                # span's attributes after the final sync
+                sp.attrs.update(moe_counts(cache))
         return time.perf_counter() - t0
 
 
@@ -350,14 +368,19 @@ class ContinuousJaxExecutor:
         st.tok = jnp.zeros((cap, 1), jnp.int32)
         st.pos = jnp.zeros((cap,), jnp.int32)
 
+        # the slab's per-layer entries are indexed by slot; the expert
+        # counters of a MoE model's cache are not and stay as they are
+        def scatter(slab, c, slot_ids):
+            return dict(slab, layers=jax.tree.map(
+                lambda s, cn: s.at[:, slot_ids].set(cn.astype(s.dtype)),
+                slab["layers"], c["layers"]))
+
         def make_join(b: int) -> Callable:
             def _join(params, slab, tok, pos, tokens, slot_ids):
                 cache = init_cache(cfg, b, max_len)
                 lg, c = prefill(cfg, params, tokens, cache)
                 first = jnp.argmax(lg, axis=-1).astype(jnp.int32)  # (b,1)
-                slab = jax.tree.map(
-                    lambda s, cn: s.at[:, slot_ids].set(cn.astype(s.dtype)),
-                    slab, c)
+                slab = scatter(slab, c, slot_ids)
                 tok = tok.at[slot_ids].set(first)
                 pos = pos.at[slot_ids].set(
                     jnp.full((b,), sm.prompt_len, jnp.int32))
@@ -366,13 +389,12 @@ class ContinuousJaxExecutor:
 
         def make_step(b: int) -> Callable:
             def _step(params, slab, tok, pos, slot_ids):
-                sub = jax.tree.map(lambda s: s[:, slot_ids], slab)
+                sub = dict(slab, layers=jax.tree.map(
+                    lambda s: s[:, slot_ids], slab["layers"]))
                 lg, c2 = decode_step_ragged(cfg, params, sub,
                                             tok[slot_ids], pos[slot_ids])
                 ntok = jnp.argmax(lg, axis=-1).astype(jnp.int32)   # (b,1)
-                slab = jax.tree.map(
-                    lambda s, cn: s.at[:, slot_ids].set(cn.astype(s.dtype)),
-                    slab, c2)
+                slab = scatter(slab, c2, slot_ids)
                 tok = tok.at[slot_ids].set(ntok)
                 pos = pos.at[slot_ids].set(pos[slot_ids] + 1)
                 return slab, tok, pos

@@ -246,3 +246,48 @@ def test_decode_attention_reads_the_cache_as_stored(one_chip, arch, layers,
         for kv in ins[kern][2][2:4]:
             assert _kv_path(ins, roots, kv, cfg.n_layers * math.prod(layer)
                             ) == ["dynamic-update-slice", "dynamic-slice"]
+
+
+@pytest.mark.parametrize("rows", [10, 80, 10240])
+def test_moe_gmm_compiles_at_granite_widths(one_chip, rows):
+    """The expert kernel over 18 held experts of d=4096, f=768, stacked
+    over 9 layers: decode's 10 and 80 rows, and 1024 tokens' prefill."""
+    from repro.kernels.moe_gmm import moe_gmm
+    L, G, d, f = 9, 18, 4096, 768
+    args = (_sds(one_chip, (rows, d)), _sds(one_chip, (L, G, d, f)),
+            _sds(one_chip, (L, G, d, f)), _sds(one_chip, (L, G, f, d)),
+            _sds(one_chip, (G,), jnp.int32), _sds(one_chip, (), jnp.int32))
+    assert _has_kernel(_compile(moe_gmm, *args))
+
+
+@pytest.mark.parametrize("step,batch", [("prefill", 1), ("decode", 8)])
+def test_granite_share_compiles_and_fits_one_chip(one_chip, step, batch):
+    """granite-4.0-h-small as the benchmark cuts it (20 layers, experts
+    0-17 held): the served step compiles with its kernels, fits beside the
+    weights, and never copies a layer's held experts out of the stacked
+    weights (the kernel reads them in place)."""
+    cfg = get_config("granite-4.0-h-small")
+    cfg = cfg.with_(n_layers=20, layer_types=cfg.layer_types[:20],
+                    experts_held=18, kernels="pallas")
+
+    def place(tree):
+        return jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype), tree)
+
+    params = place(jax.eval_shape(lambda k: init_params(cfg, k),
+                                  jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: init_cache(cfg, batch, 1056)))
+    if step == "prefill":
+        compiled = _compile(lambda p, x, c: prefill(cfg, p, x, c), params,
+                            _sds(one_chip, (batch, 1024), jnp.int32), cache)
+    else:
+        compiled = _compile(lambda p, c, x, t: decode_step(cfg, p, c, x, t),
+                            params, cache,
+                            _sds(one_chip, (batch, 1), jnp.int32),
+                            _sds(one_chip, (), jnp.int32))
+    text = compiled.as_text()
+    assert "moe_gmm" in text and _has_kernel(compiled)
+    assert not re.search(r"bf16\[(1,)?18,4096,768\]\{[^}]*\} (copy|fusion)",
+                         text)
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < HBM_BYTES
